@@ -25,6 +25,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fpformat import HOBFLOPS_FORMATS
 from repro.kernels.conv2d_bitslice.network import (ConvLayerSpec,
                                                    HobflopsNetwork,
@@ -102,6 +103,7 @@ def run_residual_graph(args, fmt, rng):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fmt", default="hobflops9",
                     choices=sorted(HOBFLOPS_FORMATS))

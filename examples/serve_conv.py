@@ -27,6 +27,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fpformat import HOBFLOPS_FORMATS
 from repro.kernels.conv2d_bitslice.network import NetworkGraph
 from repro.serve_conv import (ConvRequest, ConvServeEngine, ServePolicy,
@@ -67,6 +68,7 @@ def overload_demo(g, hwc, rng, degrade_fmt):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fmt", default="hobflops8",
                     choices=sorted(HOBFLOPS_FORMATS))

@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -484,7 +485,9 @@ def tune_conv_blocks(images, kernels, *, fmt: FPFormat,
     ``results`` maps the *resolved* (post-clamp) parameter tuple to
     seconds/call — candidates that clamp to the same launch config are
     timed once.  Only launch-relevant errors (``_LAUNCH_ERRORS``) mark
-    a candidate as failed — interrupts re-raise immediately — and if
+    a candidate as failed — interrupts re-raise immediately.  Each
+    skipped candidate emits a ``RuntimeWarning`` naming it and the
+    first line of what the compiler refused, and if
     every candidate fails the final ``RuntimeError`` names the last
     failing candidate dict and its error.
     """
@@ -520,6 +523,11 @@ def tune_conv_blocks(images, kernels, *, fmt: FPFormat,
             dt = (time.perf_counter() - t0) / iters
         except _LAUNCH_ERRORS as e:                 # unlaunchable combo
             last_err, last_cand = e, dict(cand)
+            first = (str(e).strip().splitlines() or [""])[0]
+            warnings.warn(
+                f"tune_conv_blocks: skipping candidate {cand!r}, which "
+                f"cannot launch: {type(e).__name__}: {first}",
+                RuntimeWarning, stacklevel=2)
             continue
         results[key] = dt
         if dt < best_dt:

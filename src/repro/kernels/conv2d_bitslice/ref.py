@@ -9,13 +9,16 @@ from repro.kernels.bitslice_mac.ref import hobflops_matmul_ref
 
 
 def conv2d_f32(images, kernels, stride: int = 1, padding: str = "SAME"):
-    """Plain float conv oracle (numpy, NHWC x HWIO -> NHWC)."""
+    """Plain float conv oracle (numpy, NHWC x HWIO -> NHWC), at full
+    float32 precision on every backend (a TPU would otherwise multiply
+    in bfloat16 passes)."""
     import jax
     import jax.numpy as jnp
     out = jax.lax.conv_general_dilated(
         jnp.asarray(images), jnp.asarray(kernels),
         window_strides=(stride, stride), padding=padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
     return np.asarray(out)
 
 

@@ -27,8 +27,8 @@ def pack_weights(w, sfmt: StorageFormat) -> QuantizedTensor:
 @functools.partial(jax.jit, static_argnames=("backend", "interpret",
                                              "bm", "bn", "bk"))
 def dequant_matmul(x, qt: QuantizedTensor, *, backend: str = "pallas",
-                   interpret: bool = False, bm: int = 128, bn: int = 256,
-                   bk: int = 512):
+                   interpret: bool = False, bm: int = 128, bn: int = 4096,
+                   bk: int = 256):
     """x [M, K] @ dequant(qt [K, N]) -> [M, N] f32."""
     K, N = qt.shape
     if backend == "pallas":

@@ -325,10 +325,13 @@ class ConvServeEngine:
         # "use the derived default", same as the kernel launch)
         p_block = (blocks or {}).get("p_block") \
             or derive_blocks(h * w, 1, 1)["p_block"]
-        self.max_batch = max_batch or derive_max_batch(self.hwc, p_block)
+        # the carrier-row budget holds per device: a wave mesh of n
+        # devices admits n times the one-device wave
+        n = 1 if mesh is None else mesh_size(mesh)
+        self.max_batch = max_batch or n * derive_max_batch(self.hwc,
+                                                           p_block)
         self.mesh = mesh
         if mesh is not None:
-            n = mesh_size(mesh)
             if self.max_batch % n:
                 raise ValueError(
                     f"max_batch {self.max_batch} must divide over the "

@@ -19,50 +19,59 @@ a forced 2-device host in a subprocess).
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
+import numpy as np
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.conv2d_bitslice.network import NetworkGraph
-from repro.launch.mesh import _mk
 from repro.serve_conv.errors import WaveShardingError
-
-
-def _shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:  # older jax keeps it in experimental
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
 
 
 def wave_mesh(ndev: int | None = None):
     """A 1-D ``wave`` mesh over the first ``ndev`` local devices (all
     of them by default)."""
-    n = ndev or len(jax.devices())
-    return _mk((n,), ("wave",))
+    devices = jax.devices()[:ndev]
+    return jax.make_mesh((len(devices),), ("wave",),
+                         axis_types=(AxisType.Auto,), devices=devices)
 
 
 def mesh_size(mesh) -> int:
     return int(mesh.devices.size)
 
 
+def wave_program(graph: NetworkGraph, mesh):
+    """The graph's resident runner shard_mapped over the batch axis of
+    ``mesh``, jitted: ``(images [B,H,W,C], weights) -> [B,Ho,Wo,M]``
+    with images and output split on ``wave`` and weights replicated."""
+    # check_vma=False: the graph body has no collective, and its scan
+    # carries start from fresh zeros, which the varying-axes check
+    # would reject against the wave-varying outputs.
+    return jax.jit(jax.shard_map(graph._resident_fn, mesh=mesh,
+                                 in_specs=(P("wave"), P()),
+                                 out_specs=P("wave"), check_vma=False))
+
+
 def wave_sharded_runner(graph: NetworkGraph, mesh=None):
-    """A wave entrypoint ``images [B,H,W,C] -> [B,Ho,Wo,M]`` that
-    shard_maps the graph's compiled resident runner over the batch
-    axis.  ``B`` must divide by the mesh size (the engine guarantees
-    this by scaling its batch buckets to multiples of it); weights are
-    replicated."""
+    """A wave entrypoint ``images [B,H,W,C] -> [B,Ho,Wo,M]`` running
+    :func:`wave_program`.  ``B`` must divide by the mesh size (the
+    engine guarantees this by scaling its batch buckets to multiples
+    of it).  The weights are copied to every device of the mesh once,
+    here, and the output is one array whose shards together hold the
+    whole wave."""
     mesh = mesh or wave_mesh()
     n = mesh_size(mesh)
-    fn, weights = graph._resident_fn, graph._live_weights
-    sharded = _shard_map()(fn, mesh=mesh, in_specs=(P("wave"), P()),
-                           out_specs=P("wave"))
+    weights = jax.device_put(graph._live_weights,
+                             NamedSharding(mesh, P()))
+    sharded = wave_program(graph, mesh)
+    split = NamedSharding(mesh, P("wave"))
 
     def runner(images):
-        images = jnp.asarray(images, jnp.float32)
-        if images.shape[0] % n:
+        if np.shape(images)[0] % n:
             raise WaveShardingError(
-                f"wave batch {images.shape[0]} does not divide over "
+                f"wave batch {np.shape(images)[0]} does not divide over "
                 f"the {n}-device wave mesh")
-        return sharded(images, weights)
+        # each device receives its own slab of the wave from the host
+        return sharded(jax.device_put(np.asarray(images, np.float32),
+                                      split), weights)
 
     return runner

@@ -53,10 +53,7 @@ def test_compressed_psum_shard_map():
     def f(x):
         return compressed_psum(x, "data")
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # older jax keeps it in experimental
-        from jax.experimental.shard_map import shard_map
-    y = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(x)
+    y = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x), atol=0.01)
 
 

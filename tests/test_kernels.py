@@ -221,6 +221,36 @@ def test_tune_conv_blocks_dedupe_uses_strided_patch_count():
     assert dict(key)["p_block"] == 16
 
 
+def test_tune_conv_blocks_warns_on_refused_candidate(monkeypatch):
+    """A candidate the compiler refuses is skipped with a
+    RuntimeWarning that names it and the refusal's first line, and the
+    sweep still returns the candidate that launched."""
+    from repro.kernels.conv2d_bitslice import ops
+    fmt = FPFormat(5, 2)
+    rng = np.random.default_rng(24)
+    img = _rand(rng, (1, 4, 4, 4))
+    ker = _rand(rng, (1, 1, 4, 32), 0.4)
+    real = ops.hobflops_conv2d
+
+    def conv(*a, m_block=None, **kw):
+        if m_block == 8:
+            raise ValueError("Mosaic failed to compile TPU kernel: "
+                             "block too large\nfull compiler log")
+        return real(*a, m_block=m_block, **kw)
+
+    monkeypatch.setattr(ops, "hobflops_conv2d", conv)
+    with pytest.warns(RuntimeWarning) as rec:
+        best, results = ops.tune_conv_blocks(
+            img, ker, fmt=fmt, backend="jnp", iters=1,
+            candidates=[{"m_block": 8, "p_block": 4}, {"p_block": 8}])
+    assert best == {"p_block": 8} and len(results) == 1
+    (w,) = [str(r.message) for r in rec]
+    assert "{'m_block': 8, 'p_block': 4}" in w
+    assert "ValueError: Mosaic failed to compile TPU kernel: " \
+        "block too large" in w
+    assert "full compiler log" not in w
+
+
 def test_hobflops_relu_is_bitwise():
     """ReLU in the bitslice domain == ReLU on decoded values."""
     import jax.numpy as jnp

@@ -295,7 +295,8 @@ import numpy as np
 import jax
 from repro.core.fpformat import FPFormat
 from repro.kernels.conv2d_bitslice.network import NetworkGraph
-from repro.serve_conv import wave_mesh, wave_sharded_runner
+from repro.serve_conv import (ConvServeEngine, derive_max_batch, wave_mesh,
+                              wave_sharded_runner)
 
 assert len(jax.devices()) == 2
 rng = np.random.default_rng(0)
@@ -307,6 +308,10 @@ g.output(c1)
 imgs = rng.standard_normal((4, 6, 6, 3)).astype(np.float32)
 got = np.asarray(wave_sharded_runner(g, wave_mesh())(imgs))
 np.testing.assert_array_equal(got, np.asarray(g.run(imgs)))
+assert wave_mesh(1).devices.size == 1
+# without max_batch the row budget holds per device of the mesh
+eng = ConvServeEngine(g, (6, 6, 3), mesh=wave_mesh())
+assert eng.max_batch == 2 * derive_max_batch((6, 6, 3)), eng.max_batch
 print("MULTIDEV-OK")
 """
 
@@ -316,6 +321,8 @@ def test_sharded_wave_bit_exact_two_devices():
     a subprocess: the in-process device set must stay 1) is bit-exact
     vs single-device."""
     env = dict(os.environ)
+    # the child never competes with this process for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])

@@ -66,3 +66,22 @@ def test_gates_chain_table_shape():
         cell = row[lib]
         assert cell["chain_gates_per_mac"] < cell["mac_gates"]
         assert cell["saving_pct"] > 0
+
+
+def test_compile_cache_placement(monkeypatch):
+    """An outside JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the
+    cache goes to one fixed directory at the root of the checkout.  The
+    config update is intercepted: tests never turn the cache on."""
+    import jax
+    from repro import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    placed = compile_cache.enable_compile_cache()
+    assert calls == [("jax_compilation_cache_dir", placed)]
+    assert os.path.samefile(os.path.dirname(placed), _ROOT)
+    assert os.path.basename(placed) == ".jax_cache"
