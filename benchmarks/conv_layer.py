@@ -62,4 +62,6 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_classic_cpu_emitters
+    use_classic_cpu_emitters()
     print(run()[0])

@@ -224,6 +224,8 @@ def smoke() -> bool:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_classic_cpu_emitters
+    use_classic_cpu_emitters()
     import sys
 
     if "--smoke" in sys.argv:

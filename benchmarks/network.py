@@ -164,30 +164,6 @@ def bench_residual_pool(fmt_name: str, hw: int = G_HW_, c: int = G_C_,
     }
 
 
-def smoke(fmt_name: str = "hobflops8", hw: int = 6, c: int = 8,
-          depth: int = 3) -> dict:
-    """Tiny run for the tier-1 smoke test: builds the stack, checks the
-    resident path is bit-exact vs the per-layer roundtrip, and returns
-    a result row (1 iter, 1 rep, stack reused)."""
-    stack = build_stack(fmt_name, hw, c, depth)
-    img, _, net = stack
-    res = np.asarray(net(img))
-    rt = np.asarray(net.run_roundtrip(img))
-    assert res.shape == net.out_shape(img.shape), (res.shape, img.shape)
-    assert (res == rt).all(), "resident != per-layer roundtrip"
-    # the graph workload too: residual + pools, still bit-exact
-    gimg, g = build_residual_pool(fmt_name, hw=8, c=4)
-    gres = np.asarray(g.run(gimg))
-    assert (gres == np.asarray(g.run_roundtrip(gimg))).all(), \
-        "graph resident != per-layer oracle"
-    row = bench_network(fmt_name, hw, c, depth, iters=1, reps=1,
-                        stack=stack)
-    row["residual_pool"] = bench_residual_pool(fmt_name, hw=8, c=4,
-                                               iters=1, reps=1,
-                                               stack=(gimg, g))
-    return row
-
-
 def run(quick: bool = False):
     formats = ["hobflops8", "hobflops9"] if quick else \
         ["hobflops8", "hobflops9", "hobflops10", "hobflops16"]
@@ -223,5 +199,7 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_classic_cpu_emitters
+    use_classic_cpu_emitters()
     text, _ = run()
     print(text)

@@ -44,7 +44,9 @@ def main(argv=None):
     ap.add_argument("--out-dir", default=".",
                     help="directory for BENCH_<section>.json files")
     args = ap.parse_args(argv)
-    from repro.compile_cache import enable_compile_cache
+    from repro.compile_cache import (enable_compile_cache,
+                                      use_classic_cpu_emitters)
+    use_classic_cpu_emitters()
     enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     sections = [s for s in ("gates", "macs", "conv", "network", "serve",

@@ -335,5 +335,7 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_classic_cpu_emitters
+    use_classic_cpu_emitters()
     text, _ = run()
     print(text)

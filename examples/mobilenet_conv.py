@@ -25,7 +25,8 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
-from repro.compile_cache import enable_compile_cache
+from repro.compile_cache import (enable_compile_cache,
+                                  use_classic_cpu_emitters)
 from repro.core.fpformat import HOBFLOPS_FORMATS
 from repro.kernels.conv2d_bitslice.network import (ConvLayerSpec,
                                                    HobflopsNetwork,
@@ -103,6 +104,7 @@ def run_residual_graph(args, fmt, rng):
 
 
 def main():
+    use_classic_cpu_emitters()
     enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fmt", default="hobflops9",

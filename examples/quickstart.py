@@ -15,6 +15,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro.compile_cache import use_classic_cpu_emitters
 from repro.core.fpcore import build_mac
 from repro.core.fpformat import HOBFLOPS_FORMATS
 from repro.core.opt import CELL_LIBS, tech_map
@@ -23,6 +24,7 @@ from repro.kernels.bitslice_mac.ref import hobflops_matmul_f64
 
 
 def main():
+    use_classic_cpu_emitters()
     fmt = HOBFLOPS_FORMATS["hobflops9"]
     print(f"format: hobflops9 = {fmt} "
           f"({fmt.nbits} bits incl. FloPoCo exception field)")

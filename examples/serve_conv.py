@@ -27,7 +27,8 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
-from repro.compile_cache import enable_compile_cache
+from repro.compile_cache import (enable_compile_cache,
+                                  use_classic_cpu_emitters)
 from repro.core.fpformat import HOBFLOPS_FORMATS
 from repro.kernels.conv2d_bitslice.network import NetworkGraph
 from repro.serve_conv import (ConvRequest, ConvServeEngine, ServePolicy,
@@ -68,6 +69,7 @@ def overload_demo(g, hwc, rng, degrade_fmt):
 
 
 def main():
+    use_classic_cpu_emitters()
     enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fmt", default="hobflops8",

@@ -17,6 +17,8 @@ from repro.kernels.dequant_matmul.ops import dequant_matmul, pack_weights
 from repro.kernels.dequant_matmul.ref import dequant_matmul_ref
 from repro.quant.storage import dequantize, quantize
 
+from conftest import COMPILE_HEAVY
+
 
 def _rand(rng, shape, scale=1.0):
     return (rng.standard_normal(shape) * scale).astype(np.float32)
@@ -126,6 +128,7 @@ def test_hobflops_conv2d(relu):
     np.testing.assert_array_equal(got, want)
 
 
+@COMPILE_HEAVY
 def test_bitslice_mac_c_unroll_chain():
     """Chained-channel kernel (c_unroll > 1) stays bit-exact, including
     when c_unroll does not divide C (clamping / padding paths)."""

@@ -15,6 +15,8 @@ from repro.models.mamba import (mamba, mamba_decode, mamba_schema,
 from repro.models.moe import moe, moe_dense_ref, moe_schema
 from repro.models.schema import init_params, param_count
 
+from conftest import COMPILE_HEAVY
+
 KEY = jax.random.PRNGKey(0)
 
 
@@ -111,6 +113,7 @@ def test_mamba_decode_matches_full():
 # ---------------------------------------------------------------------------
 # Per-arch smoke: forward + loss finite, gradients flow
 # ---------------------------------------------------------------------------
+@COMPILE_HEAVY
 @pytest.mark.parametrize("arch", ARCH_NAMES)
 def test_arch_smoke_train_step(arch):
     cfg = smoke_config(arch)

@@ -23,6 +23,8 @@ from repro.kernels.conv2d_bitslice.ops import (ConvWeights,
                                                encode_conv_weights)
 from repro.kernels.conv2d_bitslice.ref import conv2d_f32
 
+from conftest import COMPILE_HEAVY
+
 
 def _rand(rng, shape, scale=1.0):
     return (rng.standard_normal(shape) * scale).astype(np.float32)
@@ -39,20 +41,28 @@ def _stack(fmt, rng, cin=4, width=8):
     return ks, specs
 
 
-def test_resident_matches_roundtrip_bit_exact():
-    """The tentpole acceptance: 3 layers, single encode + single
-    decode, bit-exact to the per-layer decode/re-encode path."""
-    fmt = FPFormat(5, 2)   # hobflops8
+@pytest.fixture(scope="module")
+def stack8():
+    """The 3-layer hobflops8 stack and one image, shared by the tests
+    of this file that read it."""
     rng = np.random.default_rng(0)
     img = _rand(rng, (1, 6, 6, 4))
-    _, specs = _stack(fmt, rng)
-    net = HobflopsNetwork(specs)
+    _, specs = _stack(FPFormat(5, 2), rng)
+    return HobflopsNetwork(specs), img
+
+
+@COMPILE_HEAVY
+def test_resident_matches_roundtrip_bit_exact(stack8):
+    """The tentpole acceptance: 3 layers, single encode + single
+    decode, bit-exact to the per-layer decode/re-encode path."""
+    net, img = stack8
     res = np.asarray(net(img))
     rt = np.asarray(net.run_roundtrip(img))
     assert res.shape == net.out_shape(img.shape)
     np.testing.assert_array_equal(res, rt)
 
 
+@COMPILE_HEAVY
 def test_resident_tracks_f32_reference():
     fmt = FPFormat(5, 3)   # hobflops9
     rng = np.random.default_rng(1)
@@ -70,6 +80,7 @@ def test_resident_tracks_f32_reference():
     assert rel < 12 * 2.0 ** -fmt.w_f, rel
 
 
+@COMPILE_HEAVY
 def test_resident_mixed_formats():
     """Per-layer operand formats differ; boundary casts re-round."""
     rng = np.random.default_rng(2)
@@ -84,6 +95,7 @@ def test_resident_mixed_formats():
     np.testing.assert_array_equal(res, rt)
 
 
+@COMPILE_HEAVY
 def test_resident_pallas_backend_interpret():
     fmt = FPFormat(5, 2)
     rng = np.random.default_rng(3)
@@ -96,15 +108,11 @@ def test_resident_pallas_backend_interpret():
     np.testing.assert_array_equal(got, want)
 
 
-def test_no_f32_at_interior_boundaries():
+def test_no_f32_at_interior_boundaries(stack8):
     """The resident jaxpr contains exactly one encode (bitcast from f32)
     and one decode (bitcast to f32): interior boundaries never touch
     float32."""
-    fmt = FPFormat(5, 2)
-    rng = np.random.default_rng(4)
-    img = _rand(rng, (1, 5, 5, 4))
-    _, specs = _stack(fmt, rng)
-    net = HobflopsNetwork(specs)
+    net, img = stack8
     jaxpr = jax.make_jaxpr(lambda x: net._resident(x, net.weights))(img)
 
     from conftest import count_primitives
@@ -113,6 +121,7 @@ def test_no_f32_at_interior_boundaries():
     assert count_primitives(jaxpr.jaxpr, "bitcast_convert_type") == 2
 
 
+@COMPILE_HEAVY
 def test_resident_stride2_valid_bit_exact():
     """stride=2 and padding=VALID through the *resident* pipeline (not
     just the per-layer path): bit-exact to the roundtrip oracle, and

@@ -27,6 +27,8 @@ from repro.kernels.conv2d_bitslice.ops import (add_activations,
                                                maxpool2d_activations,
                                                relu_activations)
 
+from conftest import COMPILE_HEAVY
+
 F8 = FPFormat(5, 2)    # hobflops8
 F9 = FPFormat(5, 3)    # hobflops9
 F11 = FPFormat(5, 5)   # hobflops11
@@ -57,26 +59,32 @@ def _residual_pool_graph(rng, fmt_lo=F8, fmt_hi=F11, cin=4, width=8,
     return g
 
 
-def test_residual_pool_graph_bit_exact():
-    """Tentpole acceptance: the branched, pooled, mixed-precision graph
-    is bit-exact between the resident and f32-boundary oracle paths."""
+@pytest.fixture(scope="module")
+def residual_pool():
+    """The acceptance graph (jnp backend) and one image, shared so that
+    its resident program compiles once for this file."""
     rng = np.random.default_rng(0)
     g = _residual_pool_graph(rng)
-    img = _rand(rng, (1, 8, 8, 4))
+    return g, _rand(rng, (1, 8, 8, 4))
+
+
+@COMPILE_HEAVY
+def test_residual_pool_graph_bit_exact(residual_pool):
+    """Tentpole acceptance: the branched, pooled, mixed-precision graph
+    is bit-exact between the resident and f32-boundary oracle paths."""
+    g, img = residual_pool
     res = np.asarray(g.run(img))
     ref = np.asarray(g.run_roundtrip(img))
     assert res.shape == g.out_shape(img.shape)
     np.testing.assert_array_equal(res, ref)
 
 
-def test_strided_graph_single_encode_decode():
+def test_strided_graph_single_encode_decode(residual_pool):
     """The one-encode/one-decode invariant holds for a branched graph
     with a stride-2 conv and pooling: exactly one f32->i32 bitcast
     (entry) and one i32->f32 (exit) in the whole jaxpr."""
     from conftest import count_primitives
-    rng = np.random.default_rng(1)
-    g = _residual_pool_graph(rng)
-    img = _rand(rng, (1, 8, 8, 4))
+    g, img = residual_pool
     jaxpr = jax.make_jaxpr(
         lambda x: g._resident_fn(x, g._weights))(img)
     assert count_primitives(jaxpr.jaxpr, "bitcast_convert_type") == 2
@@ -264,14 +272,17 @@ def test_relu_activations_wrapper():
     np.testing.assert_array_equal(got, want)
 
 
-def test_graph_pallas_interpret_matches_jnp():
+@COMPILE_HEAVY
+def test_graph_pallas_interpret_matches_jnp(residual_pool):
     """The graph runner with the Pallas conv backend (interpret mode on
-    CPU) is bit-identical to the jnp backend."""
+    CPU) is bit-identical to the jnp backend.  A 6x6 image, not the
+    fixture's 8x8, leaves odd 3x3 maps after the pool (patch counts 36
+    and 9), so the stride-2 conv runs on an odd size."""
+    g, _ = residual_pool
     img = _rand(np.random.default_rng(7), (1, 6, 6, 4))
-    want = np.asarray(_residual_pool_graph(
-        np.random.default_rng(8)).run(img))
+    want = np.asarray(g.run(img))
     got = np.asarray(_residual_pool_graph(
-        np.random.default_rng(8), backend="pallas",
+        np.random.default_rng(0), backend="pallas",
         interpret=True).run(img))
     np.testing.assert_array_equal(got, want)
 
@@ -348,6 +359,7 @@ def test_validator_frozen_after_output():
         g.relu("r2", "r")
 
 
+@COMPILE_HEAVY
 def test_hobflops_network_is_linear_graph():
     """The sequential wrapper lowers onto conv0..convN nodes of a
     NetworkGraph and stays bit-exact through it."""

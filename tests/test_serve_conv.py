@@ -29,6 +29,8 @@ from repro.serve_conv import (ConvRequest, ConvServeEngine, RunnerCache,
                               wave_sharded_runner)
 from repro.serve_conv.cache import TUNE_CACHE_ENV, tune_cache_path, tune_key
 
+from conftest import COMPILE_HEAVY
+
 F8 = FPFormat(5, 2)
 F9 = FPFormat(5, 3)
 
@@ -46,6 +48,13 @@ def _graph(rng, cin=4, width=8, fmt=F8):
                 relu=True)
     g.output(g.maxpool2d("head", c2, window=2))
     return g
+
+
+@pytest.fixture(scope="module")
+def graph():
+    """One serving graph for the engine tests: its runner compiles once
+    per batch shape for the whole file."""
+    return _graph(np.random.default_rng(3))
 
 
 # ---------------------------------------------------------------------------
@@ -112,12 +121,13 @@ def test_stack_split_activations_bit_exact():
 # ---------------------------------------------------------------------------
 # engine: wave admission + bit-exactness
 # ---------------------------------------------------------------------------
-def test_engine_bit_exact_vs_per_request():
+@COMPILE_HEAVY
+def test_engine_bit_exact_vs_per_request(graph):
     """Tentpole acceptance: 5 heterogeneous requests served over a full
     wave + a ragged final wave all decode bit-identical to graph.run on
     each request alone (bucket pad included)."""
     rng = np.random.default_rng(3)
-    g = _graph(rng)
+    g = graph
     eng = ConvServeEngine(g, (8, 8, 4), max_batch=4)
     reqs = [ConvRequest(0, _rand(rng, (8, 8, 4))),
             ConvRequest(1, _rand(rng, (2, 8, 8, 4))),
@@ -141,21 +151,20 @@ def test_engine_bit_exact_vs_per_request():
     assert st["images_per_s"] > 0 and st["macs_per_s"] > 0
 
 
-def test_engine_one_encode_decode_per_wave():
+def test_engine_one_encode_decode_per_wave(graph):
     """A packed wave is one resident call: exactly one f32->i32 bitcast
     (entry encode) and one i32->f32 (exit decode) in the wave jaxpr."""
     from conftest import count_primitives
-    rng = np.random.default_rng(4)
-    g = _graph(rng)
+    g = graph
     eng = ConvServeEngine(g, (8, 8, 4), max_batch=4)
     runner, _ = eng.executor._runner(g, (8, 8, 4), 4, None)
     jaxpr = jax.make_jaxpr(runner)(np.zeros((4, 8, 8, 4), np.float32))
     assert count_primitives(jaxpr.jaxpr, "bitcast_convert_type") == 2
 
 
-def test_engine_rejects_oversized_and_misshaped():
+def test_engine_rejects_oversized_and_misshaped(graph):
     rng = np.random.default_rng(5)
-    g = _graph(rng)
+    g = graph
     eng = ConvServeEngine(g, (8, 8, 4), max_batch=2)
     with pytest.raises(ValueError, match="max_batch"):
         eng.submit(ConvRequest(0, _rand(rng, (3, 8, 8, 4))))
@@ -163,12 +172,12 @@ def test_engine_rejects_oversized_and_misshaped():
         eng.submit(ConvRequest(1, _rand(rng, (6, 6, 4))))
 
 
-def test_runner_cache_buckets_bound_compiles():
+def test_runner_cache_buckets_bound_compiles(graph):
     """Wave sizes 1/2/3/4/1 touch only buckets {1, 2, 4}: three misses,
     then hits — the compiled-program count is the bucket ladder, not
     the traffic mix."""
     rng = np.random.default_rng(6)
-    g = _graph(rng)
+    g = graph
     cache = RunnerCache()
     eng = ConvServeEngine(g, (8, 8, 4), max_batch=4, runner_cache=cache)
     for n in (1, 2, 3, 4, 1):
@@ -270,11 +279,12 @@ def test_tune_cache_env_var_override(monkeypatch, tmp_path):
 # ---------------------------------------------------------------------------
 # sharding
 # ---------------------------------------------------------------------------
-def test_sharded_wave_bit_exact_single_device():
+@COMPILE_HEAVY
+def test_sharded_wave_bit_exact_single_device(graph):
     """The shard_map path (1-device CPU mesh) equals the unsharded wave
     bit-for-bit, end to end through the engine."""
     rng = np.random.default_rng(10)
-    g = _graph(rng)
+    g = graph
     imgs = _rand(rng, (4, 8, 8, 4))
     runner = wave_sharded_runner(g, wave_mesh())
     np.testing.assert_array_equal(np.asarray(runner(imgs)),
@@ -290,7 +300,8 @@ def test_sharded_wave_bit_exact_single_device():
 
 _MULTIDEV_SCRIPT = """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=2")
 import numpy as np
 import jax
 from repro.core.fpformat import FPFormat
