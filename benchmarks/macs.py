@@ -83,8 +83,7 @@ def bench_fused(fmt: FPFormat, rounding: str = RNE,
     i_masks, w_planes = _workload(fmt, rounding)
     fn = jax.jit(functools.partial(
         fused_mac_pallas, fmt=fmt, extended=extended, rounding=rounding,
-        p_block=P_, m_block=M_ // 32, c_block=C_, c_unroll=c_unroll,
-        interpret=True))
+        c_block=C_, c_unroll=c_unroll, interpret=True))
     dt = _time(fn, i_masks, w_planes)
     return (P_ * C_ * M_) / dt, dt
 
@@ -213,8 +212,7 @@ def smoke() -> bool:
             c_unroll=ku))(i_masks, w_planes))
         got = np.asarray(jax.jit(functools.partial(
             fused_mac_pallas, fmt=fmt, extended=False, rounding=RNE,
-            p_block=P_, m_block=M_ // 32, c_block=C_, c_unroll=ku,
-            interpret=True))(i_masks, w_planes))
+            c_block=C_, c_unroll=ku, interpret=True))(i_masks, w_planes))
         same = np.array_equal(ref, got)
         ok &= same
         print(f"smoke {name}: jnp vs pallas_fused "

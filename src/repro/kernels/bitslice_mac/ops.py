@@ -106,10 +106,12 @@ def hobflops_matmul(i_f32, w_f32=None, *, fmt: FPFormat,
         assert C == C2 and nin == fmt.nbits, (w_planes.shape, fmt)
         M = cout if cout is not None else Mw * LANE
         assert M <= Mw * LANE
-    # Clamp blocks to the problem so padding never exceeds one block.
-    p_block = max(1, min(p_block, P))
+    # Clamp blocks to the problem so padding never exceeds one block;
+    # the fused kernel pads P and M to its own tile.
+    fused = backend == "pallas_fused"
+    p_block = 1 if fused else max(1, min(p_block, P))
     c_block = max(1, min(c_block, C))
-    m_block = max(1, min(m_block, -(-M // LANE)))
+    m_block = 1 if fused else max(1, min(m_block, -(-M // LANE)))
     i_masks = encode_input_masks(i_f32, fmt, rounding, p_block, c_block)
     if w_planes is None:
         w_planes = encode_weight_planes(w_f32, fmt, rounding, c_block,
@@ -124,8 +126,8 @@ def hobflops_matmul(i_f32, w_f32=None, *, fmt: FPFormat,
     elif backend == "pallas_fused":
         out = fused_mac_pallas(
             i_masks, w_planes, fmt=fmt, extended=extended,
-            rounding=rounding, p_block=p_block, m_block=m_block,
-            c_block=c_block, c_unroll=c_unroll, interpret=interpret)
+            rounding=rounding, c_block=c_block, c_unroll=c_unroll,
+            interpret=interpret)
     elif backend == "jnp":
         out = _bitslice_mac_jnp(i_masks, w_planes, fmt=fmt,
                                 extended=extended, rounding=rounding,

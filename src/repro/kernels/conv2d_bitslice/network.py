@@ -304,7 +304,9 @@ class NetworkGraph:
         ``blocks`` optionally pins launch parameters (p_block/m_block/
         c_block/c_unroll — e.g. a ``tune_conv_blocks`` winner) for this
         node's kernel launch; both runners thread them through, so a
-        tuned serving graph actually executes its tuned configuration."""
+        tuned serving graph actually executes its tuned configuration.
+        The ``pallas_fused`` kernel tiles P and M from the shapes, so on
+        that backend only c_block/c_unroll apply."""
         fmt = fmt or self.input_fmt
         if blocks:
             bad = set(blocks) - {"p_block", "m_block", "c_block",
@@ -312,6 +314,11 @@ class NetworkGraph:
             if bad:
                 raise GraphValidationError(
                     f"conv {name!r}: unknown launch block keys {bad}")
+            tiled = set(blocks) & {"p_block", "m_block"}
+            if tiled and self.backend == "pallas_fused":
+                raise GraphValidationError(
+                    f"conv {name!r}: the pallas_fused kernel tiles P and "
+                    f"M from the shapes; {sorted(tiled)} do not apply")
         if isinstance(kernels, ConvWeights):
             w = kernels
             if w.fmt != fmt:

@@ -345,11 +345,14 @@ def derive_blocks(P: int, K: int, M: int, *, p_block: int | None = None,
                   c_unroll: int | None = None) -> dict:
     """Launch parameters for a [P, K] @ [K, M] bitslice GEMM.
 
-    Defaults follow the TPU vreg geometry: 8 sublanes of output pixels
-    per tile (``p_block``), up to 128 int32 lane words of kernels
-    (``m_block`` — *not* 1, and never padding M past the next lane-word
-    multiple), the full reduction in VMEM when it fits (``c_block``) and
-    4 chained channels per netlist call (``c_unroll``).  Every value is
+    ``p_block`` and ``m_block`` tile the gate-interpreter Pallas kernel
+    (``bitslice_mac_pallas``) and pad the ``jnp`` backend's operands: 8
+    sublanes of output pixels per tile, up to 128 int32 lane words of
+    kernels (never padding M past the next lane-word multiple).  The
+    fused kernel takes neither and tiles P and M from the shapes
+    (``pallas_backend.tile_plan``).  Every backend takes ``c_block``
+    channels resident in VMEM per grid step (up to 64) and ``c_unroll``
+    chained channels per netlist call (default 4).  Every value is
     clamped to the problem size; explicit arguments win (the autotune
     sweep passes candidates through here).  See DESIGN.md §5.
     """
@@ -391,6 +394,21 @@ def conv_core(act: BitsliceActivation, weights: ConvWeights, *,
         weights.cout
     blk = derive_blocks(P, K, M, p_block=p_block, m_block=m_block,
                         c_block=c_block, c_unroll=c_unroll)
+    if backend == "pallas_fused":
+        # The fused kernel picks its own P and M tile from the shapes
+        # (``tile_plan``) and pads P, M and C itself; it absorbs the ReLU
+        # epilogue (two in-kernel ops on the final C step) — no post-hoc
+        # hobflops_relu_planes pass, the whole layer is one pallas_call.
+        if p_block is not None or m_block is not None:
+            raise ValueError("the pallas_fused backend tiles P and M from "
+                             "the shapes; p_block/m_block do not apply")
+        out = fused_mac_pallas(i_masks, weights.planes, fmt=weights.fmt,
+                               extended=extended, rounding=rounding,
+                               relu=relu, interpret=interpret,
+                               c_block=blk["c_block"],
+                               c_unroll=blk["c_unroll"])
+        return BitsliceActivation(out, weights.fmt.mult_out(extended),
+                                  (B, Ho, Wo, M))
     i_masks = _pad_to(_pad_to(i_masks, blk["p_block"], 0),
                       blk["c_block"], 1)
     w_planes = _pad_to(_pad_to(weights.planes, blk["c_block"], 0),
@@ -399,19 +417,12 @@ def conv_core(act: BitsliceActivation, weights: ConvWeights, *,
         out = bitslice_mac_pallas(i_masks, w_planes, fmt=weights.fmt,
                                   extended=extended, rounding=rounding,
                                   interpret=interpret, **blk)
-    elif backend == "pallas_fused":
-        # The fused backend absorbs the ReLU epilogue into the kernel
-        # (two in-kernel ops on the final C step) — no post-hoc
-        # hobflops_relu_planes pass, the whole layer is one pallas_call.
-        out = fused_mac_pallas(i_masks, w_planes, fmt=weights.fmt,
-                               extended=extended, rounding=rounding,
-                               relu=relu, interpret=interpret, **blk)
     else:
         out = _bitslice_mac_jnp(i_masks, w_planes, fmt=weights.fmt,
                                 extended=extended, rounding=rounding,
                                 c_unroll=blk["c_unroll"])
     fmt_out = weights.fmt.mult_out(extended)
-    if relu and backend != "pallas_fused":
+    if relu:
         out = hobflops_relu_planes(out, fmt_out)
     return BitsliceActivation(out, fmt_out, (B, Ho, Wo, M))
 
@@ -463,14 +474,17 @@ def default_tune_candidates(backend: str = "jnp") -> list[dict]:
     """Backend-aware candidate set for :func:`tune_conv_blocks`.
 
     The gate-interpreter backends sweep the full c_unroll x m_block
-    cross.  The fused backend drops ``c_unroll=8``: its win comes from
-    the single-kernel emission rather than chain depth, wide formats
-    are clamped to ``k=1`` anyway (``fused_chain_k``), and every extra
-    chain depth is another multi-minute XLA compile in the sweep.
+    cross.  The fused backend sweeps ``c_unroll`` alone: its kernel
+    tiles P and M from the shapes (``tile_plan``), and it drops
+    ``c_unroll=8``, since its win comes from the single-kernel emission
+    rather than chain depth, wide formats are clamped to ``k=1`` anyway
+    (``fused_chain_k``), and every extra chain depth is another
+    multi-minute XLA compile in the sweep.
     """
-    unrolls = (1, 2, 4) if backend == "pallas_fused" else (1, 2, 4, 8)
+    if backend == "pallas_fused":
+        return [{"c_unroll": u} for u in (1, 2, 4)]
     return [{"c_unroll": u, "m_block": m}
-            for u in unrolls for m in (8, 32, 128)]
+            for u in (1, 2, 4, 8) for m in (8, 32, 128)]
 
 
 def tune_conv_blocks(images, kernels, *, fmt: FPFormat,

@@ -6,6 +6,8 @@ oracle on every format x rounding (exhaustive on the smallest format,
 randomized wide-lane elsewhere), the register file must fail loudly on
 overflow, and a fused conv must emit exactly one ``pallas_call``.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -18,10 +20,14 @@ from repro.core.fpformat import HOBFLOPS_FORMATS, RNE, RTZ
 from repro.core.opt import optimize_mapped
 from repro.core.pallas_backend import (STACK_MAX_DEFAULT,
                                        RegisterFileOverflow,
-                                       fused_chain_k, lower_netlist)
-from repro.kernels.bitslice_mac.ops import hobflops_matmul
+                                       fused_chain_k, fused_mac_pallas,
+                                       lower_netlist, tile_plan)
+from repro.kernels.bitslice_mac.ops import (_bitslice_mac_jnp,
+                                            hobflops_matmul)
 from repro.kernels.conv2d_bitslice.network import NetworkGraph
-from repro.kernels.conv2d_bitslice.ops import hobflops_conv2d
+from repro.kernels.conv2d_bitslice.ops import (default_tune_candidates,
+                                               hobflops_conv2d,
+                                               hobflops_relu_planes)
 
 F8 = HOBFLOPS_FORMATS["hobflops8"]
 F16 = HOBFLOPS_FORMATS["hobflops16"]
@@ -219,3 +225,94 @@ def test_fused_network_graph_end_to_end():
     b = fused.run(img)
     assert np.array_equal(np.asarray(a), np.asarray(b))
     assert ref.signature() != fused.signature()
+
+
+# ---------------------------------------------------------------------------
+# Output tiles: every (P, Mw) regime of tile_plan
+# ---------------------------------------------------------------------------
+# format: (chain depth, C) — hobflops8 on the plain-stack bus at depth
+# 4, hobflops16 on the or-tree bus at depth 1.
+_TILE_FORMATS = {"hobflops8": (4, 8), "hobflops16": (1, 4)}
+_TILE_P, _TILE_MW = 1100, 16
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_operands(name):
+    """Random masks [P, C, NIN] and weight planes [C, NIN, Mw] at the
+    largest case's size, and the jnp backend's ReLU'd planes for them.
+    Every (pixel, word) of the MAC is independent of the others, so a
+    case slices its operands and the reference out of these: the jnp
+    chain compiles once per format."""
+    fmt = HOBFLOPS_FORMATS[name]
+    k, C = _TILE_FORMATS[name]
+    rng = np.random.default_rng(fmt.nbits)
+    codes = rng.integers(0, 1 << fmt.nbits, (_TILE_P, C))
+    masks = -((codes[..., None] >> np.arange(fmt.nbits)) & 1)
+    planes = rng.integers(-2**31, 2**31, (C, fmt.nbits, _TILE_MW),
+                          dtype=np.int64)
+    masks, planes = masks.astype(np.int32), planes.astype(np.int32)
+    want = _bitslice_mac_jnp(jnp.asarray(masks), jnp.asarray(planes),
+                             fmt=fmt, extended=False, rounding=RNE,
+                             c_unroll=k)
+    want = hobflops_relu_planes(want, fmt.mult_out(False))
+    return masks, planes, np.asarray(want)
+
+
+@pytest.mark.parametrize("name,P,Mw,c_block", [
+    ("hobflops8", 1100, 1, 4),   # 8 pixel groups, 1024-pixel tiles
+    ("hobflops8", 20, 2, 4),     # 4 groups, P below one tile
+    ("hobflops8", 140, 16, 4),   # 8-word rows: 2 M blocks, 2nd P tile partial
+    ("hobflops16", 600, 4, 3),   # 2 groups, 256-pixel tiles; C 4 -> 6
+    ("hobflops16", 20, 16, 2),   # 8-word rows, P below one 128-pixel row
+])
+def test_fused_mac_tiles_match_jnp(name, P, Mw, c_block):
+    """The fused kernel (interpret mode) is bit-identical to the jnp
+    backend in every tile regime, with the ReLU epilogue on, over two
+    C grid steps (one case pads C to the block with +0 codes)."""
+    fmt = HOBFLOPS_FORMATS[name]
+    k, _ = _TILE_FORMATS[name]
+    masks, planes, want = _tile_operands(name)
+    got = fused_mac_pallas(jnp.asarray(masks[:P]),
+                           jnp.asarray(planes[..., :Mw]), fmt=fmt,
+                           rounding=RNE, c_block=c_block, c_unroll=k,
+                           relu=True, interpret=True)
+    assert got.shape == (fmt.mult_out(False).nbits, P, Mw)
+    assert np.array_equal(np.asarray(got), want[:, :P, :Mw])
+
+
+@pytest.mark.parametrize("P,Mw,fill", [
+    (12544, 2, 0.98),          # ResNet-18 stem, 224x224
+    (3136, 2, 0.875),          # ResNet-18 stage 1
+    (784, 4, 0.765625),        # ResNet-18 stage 2
+    (196, 8, 0.765625),        # ResNet-18 stage 3
+    (49, 16, 0.3828125),       # ResNet-18 stage 4
+    (4096, 1, 1.0),            # ResNet-20 stage 1, a wave of 4 images
+    (1024, 1, 1.0),            # ResNet-20 stage 2, a wave of 4 images
+    (256, 2, 0.5),             # ResNet-20 stage 3, a wave of 4 images
+])
+def test_tile_plan_fill_for_resnet_convs(P, Mw, fill):
+    """The share of tile words that carry a real (pixel, channel word)
+    pair, for the convs PERF.md lists; a tile is one (8, 128) vreg."""
+    t = tile_plan(P, Mw)
+    assert t.fill == pytest.approx(fill)
+    assert t.words * t.groups == 8 and t.lanes <= 128
+    assert t.p_pad >= P and t.mw_pad >= Mw
+
+
+def test_fused_backend_refuses_p_and_m_blocks():
+    """The fused kernel tiles P and M from the shapes: a launch override
+    naming p_block/m_block is refused, not dropped in silence, and the
+    tune sweep does not offer one."""
+    ker = np.zeros((3, 3, 4, 8), np.float32)
+    for blocks in ({"p_block": 8}, {"m_block": 1, "c_unroll": 2}):
+        g = NetworkGraph(F8, backend="pallas_fused", interpret=True)
+        with pytest.raises(ValueError, match="tiles P and M"):
+            g.conv("c1", g.input_name, ker, blocks=blocks)
+    g = NetworkGraph(F8, backend="jnp")
+    g.conv("c1", g.input_name, ker, blocks={"p_block": 8, "m_block": 1})
+    img = np.zeros((1, 4, 4, 4), np.float32)
+    with pytest.raises(ValueError, match="p_block/m_block"):
+        hobflops_conv2d(img, ker, fmt=F8, backend="pallas_fused",
+                        interpret=True, m_block=1)
+    assert all(set(c) == {"c_unroll"}
+               for c in default_tune_candidates("pallas_fused"))

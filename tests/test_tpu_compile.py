@@ -87,12 +87,23 @@ def _mac_shapes(P, K, M, fmt):
     ("hobflops9", 3136, 576, 64),      # ResNet-18 stage-1 3x3 conv
     ("hobflops16", 3136, 576, 64),
     ("hobflops9", 12544, 147, 64),     # ResNet-18 7x7/2 stem
+    ("hobflops9", 49, 4608, 512),      # ResNet-18 stage 4: 16 words
+    ("hobflops16", 4096, 144, 16),     # ResNet-20 stage 1, 4 images
 ])
 def test_fused_mac_compiles(one_chip, name, P, K, M):
+    """Every tile regime of ``tile_plan``: pixel groups folded onto
+    the sublanes (1 and 2 channel words), and 8-word rows with one
+    block below a full 128-pixel row (P = 49)."""
     fmt = HOBFLOPS_FORMATS[name]
-    i_s, w_s, blk = _mac_shapes(P, K, M, fmt)
-    fn = functools.partial(fused_mac_pallas, fmt=fmt, relu=True, **blk)
-    assert "tpu_custom_call" in _compiled_text(fn, one_chip, i_s, w_s)
+    blk = derive_blocks(P, K, M)
+    # As conv_core launches it: C padded to c_block, P and M unpadded.
+    K = -(-K // blk["c_block"]) * blk["c_block"]
+    fn = functools.partial(fused_mac_pallas, fmt=fmt, relu=True,
+                           c_block=blk["c_block"],
+                           c_unroll=blk["c_unroll"])
+    assert "tpu_custom_call" in _compiled_text(
+        fn, one_chip, ((P, K, fmt.nbits), jnp.int32),
+        ((K, fmt.nbits, -(-M // LANE)), jnp.int32))
 
 
 def test_bitslice_mac_compiles(one_chip):
